@@ -34,6 +34,7 @@ from benchmarks import (  # noqa: E402
     wire_compression,
 )
 from benchmarks.common import FULL, QUICK, emit  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
 BENCHES = {
     "fig3": fig3_loss_accuracy.run,
@@ -69,6 +70,7 @@ def main() -> None:
                     "vecavg/paged-attention timing rows instead of reusing "
                     "experiments/dryrun/*.json)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.list:
         for name in BENCHES:

@@ -21,6 +21,7 @@ import numpy as np
 from repro.data.partition import partition_case3
 from repro.data.synthetic import Dataset, binarize_even_odd, make_classification
 from repro.fed.prototype import FedVecaClient, FedVecaServer
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model_by_name
 
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--serial", action="store_true",
                     help="literal per-client dispatch loop (testbed mode)")
     args = ap.parse_args()
+    use_compile_cache()
 
     orig = make_classification(2000, (784,), 10, seed=0)
     train = binarize_even_odd(orig)

@@ -12,6 +12,7 @@ import numpy as np
 from repro.data.partition import client_weights, partition_by_label, partition_case3, partition_iid
 from repro.data.synthetic import Dataset, binarize_even_odd, make_classification
 from repro.fed.simulator import FederatedSimulator, FedSimConfig, centralized_sgd, fair_fixed_tau
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model_by_name
 
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--overlap", type=int, default=1,
                     help="rounds in flight before host sync (0 = sync mode)")
     args = ap.parse_args()
+    use_compile_cache()
 
     print(f"== FedVeca quickstart: SVM / Case {args.case} / {args.clients} clients ==")
     orig = make_classification(4000, (784,), 10, seed=0)

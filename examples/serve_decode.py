@@ -7,12 +7,15 @@ single slot-masked ``decode_step`` over all live requests, and EOS /
 max-len retirement frees slots for immediate reuse.
 
     PYTHONPATH=src python examples/serve_decode.py --arch starcoder2-3b
-    PYTHONPATH=src python examples/serve_decode.py --serial   # old loop
-    PYTHONPATH=src python examples/serve_decode.py --check    # parity
-    PYTHONPATH=src python examples/serve_decode.py --paged --pages 16
-    PYTHONPATH=src python examples/serve_decode.py --paged --prefix-cache \
-        --prefill-chunk 16 --preempt          # §12.2 front-end scheduler
-    PYTHONPATH=src python examples/serve_decode.py --temperature 0.8 --top-k 20
+    PYTHONPATH=src python examples/serve_decode.py --reduced --serial
+    PYTHONPATH=src python examples/serve_decode.py --reduced --check
+    PYTHONPATH=src python examples/serve_decode.py --reduced --paged --pages 16
+    PYTHONPATH=src python examples/serve_decode.py --reduced --paged \
+        --prefix-cache --prefill-chunk 16 --preempt  # §12.2 scheduler
+    PYTHONPATH=src python examples/serve_decode.py --reduced --temperature 0.8
+
+The model runs at its published widths unless ``--reduced`` picks the
+tiny CPU-sized variant (configs/base.py ``reduced()``).
 
 ``--serial`` keeps the old request-at-a-time loop (the parity oracle);
 ``--check`` runs both and asserts token-for-token identical streams;
@@ -27,6 +30,7 @@ import sys
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model_by_name
 from repro.serve import (
     PagedServeLoop,
@@ -45,6 +49,9 @@ def clone(reqs):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="starcoder2-3b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny CPU-sized widths (default: the published "
+                    "widths, which need an accelerator)")
     ap.add_argument("--slots", type=int, default=8, help="B_slots")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--rate", type=float, default=2.0, help="arrivals/tick")
@@ -80,10 +87,11 @@ def main():
                     help="top-k sampling cutoff (0 = full vocab)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     sampler = SamplerConfig(temperature=args.temperature, top_k=args.top_k,
                             seed=args.seed)
-    model = build_model_by_name(args.arch, reduced=True)  # CPU-sized
+    model = build_model_by_name(args.arch, reduced=args.reduced)
     cfg = model.config
     try:  # fail fast + clearly (whisper: no decode path; vlm: no patches;
         # xlstm: no KV to page)
@@ -102,7 +110,7 @@ def main():
     except ServeUnsupportedError as e:
         print(f"serve_decode: {e}", file=sys.stderr)
         sys.exit(2)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))  # one program
     serve_loop.params = params
 
     reqs = poisson_trace(

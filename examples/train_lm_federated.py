@@ -23,6 +23,7 @@ from repro.checkpoint.io import restore, save
 from repro.configs import get_arch
 from repro.data.synthetic import Dataset, make_lm_tokens
 from repro.fed.simulator import FederatedSimulator, FedSimConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 
 
@@ -62,6 +63,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = lm_config(args.preset)
     model = build_model(cfg)

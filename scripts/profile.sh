@@ -41,9 +41,11 @@ done
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"  # no dataset warnings
 
 # probe the backend BEFORE exporting flags: step markers are a TPU-only
-# XLA flag and CPU/GPU jaxlib aborts at flag parse if it sees them
+# XLA flag and CPU/GPU jaxlib aborts at flag parse if it sees them. One
+# probe process, which exits (and frees the chip) before the real command;
+# Pallas interprets exactly on the CPU (kernels.auto_interpret).
 BACKEND=$(python -c 'import jax; print(jax.default_backend())')
-INTERP=$(python -c 'from repro.kernels import auto_interpret; print("interpret" if auto_interpret() else "compile")')
+if [ "$BACKEND" = cpu ]; then INTERP=interpret; else INTERP=compile; fi
 
 # step markers bracket the outer loop for the TPU profiler; dump flags
 # write the optimized HLO so kernel fusions can be inspected offline

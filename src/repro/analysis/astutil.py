@@ -104,7 +104,7 @@ def _resolve_fn_arg(arg: ast.AST) -> Optional[str]:
 #: call targets whose first function-valued argument is traced
 TRACE_ENTRY_CALLS = (
     "jax.jit", "jit", "pjit", "jax.pmap",
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map", "jax.shard_map", "jax.experimental.shard_map.shard_map",
     "pl.pallas_call", "pallas_call", "jax.experimental.pallas.pallas_call",
 )
 

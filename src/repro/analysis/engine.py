@@ -1,6 +1,6 @@
 """repro-lint engine: walk .py files, parse once, run the rule catalog.
 
-Pure stdlib (ast + tomli) — importing this module must never touch jax,
+Pure stdlib (ast + tomllib) — importing this module must never touch jax,
 so the lint stage runs first in CI and on accelerator-free machines.
 """
 from __future__ import annotations

@@ -63,10 +63,10 @@ class AllowlistError(ValueError):
 
 def load_allowlist(path: str) -> List[AllowEntry]:
     """Parse ``allowlist.toml``: a list of ``[[allow]]`` tables."""
-    import tomli
+    import tomllib
 
     with open(path, "rb") as f:
-        data = tomli.load(f)
+        data = tomllib.load(f)
     entries = []
     for i, raw in enumerate(data.get("allow", [])):
         missing = [k for k in ("rule", "path", "reason") if k not in raw]

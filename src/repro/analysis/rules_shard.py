@@ -23,7 +23,8 @@ from repro.analysis import astutil
 from repro.analysis.astutil import Rule
 from repro.analysis.findings import Finding
 
-_SHARD_ENTRIES = ("shard_map", "jax.experimental.shard_map.shard_map")
+_SHARD_ENTRIES = ("shard_map", "jax.shard_map",
+                  "jax.experimental.shard_map.shard_map")
 
 _GATHERS = {"gather", "dynamic_slice", "take", "take_along_axis",
             "all_gather"}

@@ -39,6 +39,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.analysis import sanitize as _sanitize
 from repro.core.engine import RoundEngine
@@ -158,6 +159,12 @@ class TrainDriver:
         rng = np.random.default_rng(self.seed)
         key = jax.random.PRNGKey(self.seed)
         cstate = engine.init_controller_state(params, taus)
+        if engine.mesh is not None:
+            # place round 0's params where every later round's come from
+            # (replicated on the client mesh): host-placed params would be
+            # a second input sharding and compile the round twice
+            params = jax.device_put(
+                params, NamedSharding(engine.mesh, PartitionSpec()))
         scaffold = None
         pending: deque = deque()
         self.host_blocked_s = 0.0

@@ -44,7 +44,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.controller import ControllerCore
@@ -328,7 +328,7 @@ class RoundEngine:
             out_specs = (rs(params), stats_spec, scaf_spec, cspec, res_spec)
             return shard_map(
                 sharded_body, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False,
+                out_specs=out_specs, check_vma=False,
             )(params, data, key, batches, tau, p, gprev_sqnorm, scaffold,
               cohort, residual)
 
@@ -500,7 +500,7 @@ class RoundEngine:
             ), res_spec)
             return shard_map(
                 sharded_wave, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False,
+                out_specs=out_specs, check_vma=False,
             )(params, data, key, taus, gprev_sqnorm, cohort, residual)
 
         # buffered wave dispatch needs the device data path (shards)
@@ -563,6 +563,25 @@ class RoundEngine:
         device->host surface of the fused step. params, cstate, and
         scaffold buffers are DONATED when cfg.donate.
         """
+        args = self._fused_args(params, cstate, p, key, batches, scaffold,
+                                cohort)
+        with _quiet_donation():
+            new_params, new_cstate, new_scaffold, new_res, diag = \
+                self._fused(*args)
+        if self._wire_active:
+            self._wire_res = new_res
+        return new_params, new_cstate, new_scaffold, diag
+
+    def lower_fused(self, params, cstate, p, *, key=None, batches=None,
+                    scaffold: Optional[ScaffoldState] = None, cohort=None):
+        """``run_fused``'s program lowered for the current backend, without
+        running it (``jax.stages.Lowered``: ``.as_text()`` shows which
+        kernels the round calls, e.g. a ``tpu_custom_call`` for the Pallas
+        reduce)."""
+        return self._fused.lower(*self._fused_args(
+            params, cstate, p, key, batches, scaffold, cohort))
+
+    def _fused_args(self, params, cstate, p, key, batches, scaffold, cohort):
         if self.controller is None:
             raise ValueError("engine built without controller=ControllerCore")
         data = self._resolve_data(batches, key)
@@ -570,14 +589,8 @@ class RoundEngine:
         cohort = self._prep_cohort(cohort)
         scaffold = self._materialize_scaffold(scaffold, params, self.controller.C)
         residual = self._wire_state(params, self.controller.C)
-        with _quiet_donation():
-            new_params, new_cstate, new_scaffold, new_res, diag = self._fused(
-                params, cstate, data, key, batches, p, scaffold, cohort,
-                residual,
-            )
-        if self._wire_active:
-            self._wire_res = new_res
-        return new_params, new_cstate, new_scaffold, diag
+        return (params, cstate, data, key, batches, p, scaffold, cohort,
+                residual)
 
     def _prep_cohort(self, cohort):
         """Host-side cohort normalization. Single-device: int32 [m].

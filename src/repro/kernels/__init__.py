@@ -1,25 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
 """Shared kernel-package helpers."""
 from __future__ import annotations
-
-import os
 
 import jax
 
 
 def auto_interpret() -> bool:
     """Single source of truth for the Pallas interpret-mode default:
-    interpret on CPU (and any non-TPU backend), native compile on TPU.
-
-    Override with ``REPRO_PALLAS_INTERPRET=1`` (force interpret — e.g. to
-    debug a kernel on an accelerator host) or ``=0`` (force the compile
-    path — e.g. to smoke the lowering on a GPU backend). Every kernel
-    ops.py routes through here so the policy can never drift between
-    kernels again.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env.strip() != "":
-        return env.strip() not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    interpret exactly when the backend is the CPU, the native compile
+    everywhere else. There is no override, so nothing can put a kernel
+    into the emulator on a TPU. Every kernel ops.py routes through here so
+    the policy can never drift between kernels."""
+    return jax.default_backend() == "cpu"

@@ -7,20 +7,22 @@ per tick: the read side gathers every slot's pages into a dense
 "mask" write builds a B x n_pages x page_size one-hot selector over the
 WHOLE pool. This kernel does neither:
 
-  * grid (B, Hkv, P) with the page axis innermost. The page table rides
+  * grid (B, P) with the page axis innermost. The page table rides
     in as a SCALAR-PREFETCH operand (pltpu.PrefetchScalarGridSpec), so
     the K/V pool BlockSpec index maps read ``page_table[b, p]`` directly
-    and stream exactly one physical [page_size, hd] tile per grid step —
-    the gather never exists. Unallocated entries (-1) clamp to page 0;
+    and stream exactly one physical [page_size, Hkv, hd] page per grid
+    step — the gather never exists. Unallocated entries (-1) clamp to page 0;
     their rows are masked invalid so the values never matter.
-  * online softmax across the page walk: the [G, hd] output tile (G =
-    grouped query heads per KV head), running max and running denominator
-    persist in VMEM across the P sweep (their index maps are independent
-    of the page axis) — the flash-attention recurrence, per slot.
+  * online softmax across the page walk: the [Hkv, G, hd] output tile (G
+    = grouped query heads per KV head), running max and running
+    denominator persist in VMEM across the P sweep (the output's index map
+    is independent of the page axis; the stats are scratch) — the
+    flash-attention recurrence, per slot.
   * validity is recomputed ARITHMETICALLY per tile, reproducing
     attention.paged_slot_valid bit-for-bit: entry i of a slot is valid iff
     its page is allocated and ``i <= pos`` (full) or ``i < W and
-    pos - ((pos - i) mod W) >= 0`` (SWA ring).
+    pos - ((pos - i) mod W) >= 0`` (SWA ring, which reduces to ``i < W
+    and i <= pos``).
   * the new token's K/V row is written through a routed one-row output
     block aliased onto the pool (input_output_aliases): slot b's write
     block sits at physical page ``page_table[b, idx // ps]`` row ``idx %
@@ -64,10 +66,11 @@ NEG_INF = -1e30
 def _decode_kernel(pt_ref, pos_ref, act_ref, wpage_ref, wrow_ref,  # prefetch
                    q_ref, kpool_ref, vpool_ref, knew_ref, vnew_ref,
                    kwrite_ref, vwrite_ref,
-                   o_ref, m_ref, l_ref, kout_ref, vout_ref, *,
-                   scale: float, window: int, ps: int, n_pages_slot: int):
+                   o_ref, kout_ref, vout_ref, m_ref, l_ref, *,
+                   scale: float, window: int, ps: int, n_pages_slot: int,
+                   hkv: int):
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -81,44 +84,46 @@ def _decode_kernel(pt_ref, pos_ref, act_ref, wpage_ref, wrow_ref,  # prefetch
     alloc = entry >= 0
     idx = (pos % window) if window else pos  # the new token's slot index
 
-    q = q_ref[0].astype(jnp.float32)  # [G, hd]
-    k = kpool_ref[0, :, 0, :].astype(jnp.float32)  # [ps, hd]
-    v = vpool_ref[0, :, 0, :].astype(jnp.float32)
-
     # inject the new token's row in-register: correctness is then
     # independent of whether the aliased pool write has landed yet
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
     inject = active & alloc & (p == idx // ps)
     rowhit = inject & (row_iota == idx % ps)  # [ps, 1]
-    k = jnp.where(rowhit, knew_ref[0].astype(jnp.float32), k)
-    v = jnp.where(rowhit, vnew_ref[0].astype(jnp.float32), v)
 
-    s = (q @ k.T) * scale  # [G, ps]
-
-    # arithmetic validity == attention.paged_slot_valid for this tile
+    # arithmetic validity == attention.paged_slot_valid for this tile. For
+    # the SWA ring, entry i < W holds position pos - ((pos - i) mod W),
+    # which is >= 0 exactly when i <= pos.
     i = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)  # [1, ps]
+    valid = alloc & (i <= pos)
     if window:
-        valid = alloc & (i < window) & (pos - ((pos - i) % window) >= 0)
-    else:
-        valid = alloc & (i <= pos)
-    s = jnp.where(valid, s, NEG_INF)
+        valid = valid & (i < window)
 
-    m_prev = m_ref[0]  # [G]
-    l_prev = l_ref[0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    pexp = jnp.exp(s - m_new[:, None])
-    pexp = jnp.where(valid, pexp, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    o_ref[0] = o_ref[0] * corr[:, None] + pexp @ v
-    m_ref[0] = m_new
-    l_ref[0] = l_prev * corr + jnp.sum(pexp, axis=-1)
+    for h in range(hkv):  # kv heads; each serves G grouped query heads
+        q = q_ref[0, h].astype(jnp.float32)  # [G, hd]
+        k = kpool_ref[0, :, h, :].astype(jnp.float32)  # [ps, hd]
+        v = vpool_ref[0, :, h, :].astype(jnp.float32)
+        k = jnp.where(rowhit, knew_ref[0, h:h + 1, :].astype(jnp.float32), k)
+        v = jnp.where(rowhit, vnew_ref[0, h:h + 1, :].astype(jnp.float32), v)
+
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+        s = jnp.where(valid, s, NEG_INF)  # [G, ps]
+
+        m_prev = m_ref[h]  # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
+        pexp = jnp.where(valid, pexp, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        o_ref[0, h] = o_ref[0, h] * corr + pexp @ v
+        m_ref[h] = m_new
+        l_ref[h] = l_ref[h] * corr + jnp.sum(pexp, axis=-1, keepdims=True)
 
     @pl.when(p == n_pages_slot - 1)
     def _final():
-        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-30)[:, None]
+        for h in range(hkv):
+            o_ref[0, h] = o_ref[0, h] / jnp.maximum(l_ref[h], 1e-30)
 
-    # fused pool write: this (b, h, p)-invariant-in-(h, p) block lands at
-    # the routed (page, row); duplicates carry identical bytes
+    # fused pool write: this p-invariant block lands at the routed
+    # (page, row); duplicates carry identical bytes
     kout_ref[0, 0] = kwrite_ref[0]
     vout_ref[0, 0] = vwrite_ref[0]
 
@@ -131,7 +136,13 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, k_new, v_new,
     """q [B,Hq,hd], pools [N,ps,Hkv,hd], k_new/v_new [B,Hkv,hd],
     page_table [B,P] int32 (-1 = unallocated), pos [B], active bool [B]
     -> (o [B,Hq,hd], k_pool', v_pool') with the new token's row written
-    into the pools for every active slot (others bit-identical)."""
+    into the pools for every active slot (others bit-identical).
+
+    TPU layout: grid (B, P), one physical page of every KV head per step.
+    Every block's two trailing dims are whole array dims — q/o ride as
+    [B, Hkv, G, hd], pool pages as [ps, Hkv, hd], the new rows as
+    [Hkv, hd] — so any G, Hkv and page size tile legally; the running
+    max/denominator live in VMEM scratch."""
     B, Hq, hd = q.shape
     N, ps, Hkv, _ = k_pool.shape
     P = page_table.shape[1]
@@ -156,50 +167,59 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, k_new, v_new,
     kwrite = jnp.where(any_ok, k_new[src], jnp.broadcast_to(k_pool[0, 0], k_new.shape))
     vwrite = jnp.where(any_ok, v_new[src], jnp.broadcast_to(v_pool[0, 0], v_new.shape))
 
-    def _pool_route(b, h, p, pt_ref, *_):
-        return (jnp.maximum(pt_ref[b, p], 0), 0, h, 0)
+    def _pool_route(b, p, pt_ref, *_):
+        return (jnp.maximum(pt_ref[b, p], 0), 0, 0, 0)
 
-    def _write_route(b, h, p, pt_ref, pos_ref, act_ref, wpage_ref, wrow_ref):
+    def _write_route(b, p, pt_ref, pos_ref, act_ref, wpage_ref, wrow_ref):
         return (wpage_ref[b], wrow_ref[b], 0, 0)
+
+    def _slot(b, p, *_):
+        return (b, 0, 0)
+
+    def _slot4(b, p, *_):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(B, Hkv, P),
+        grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, G, hd), lambda b, h, p, *_: (b, h, 0)),  # q
-            pl.BlockSpec((1, ps, 1, hd), _pool_route),  # k_pool page
-            pl.BlockSpec((1, ps, 1, hd), _pool_route),  # v_pool page
-            pl.BlockSpec((1, 1, hd), lambda b, h, p, *_: (b, h, 0)),  # k_new
-            pl.BlockSpec((1, 1, hd), lambda b, h, p, *_: (b, h, 0)),  # v_new
-            pl.BlockSpec((1, Hkv, hd), lambda b, h, p, *_: (b, 0, 0)),  # kwrite
-            pl.BlockSpec((1, Hkv, hd), lambda b, h, p, *_: (b, 0, 0)),  # vwrite
+            pl.BlockSpec((1, Hkv, G, hd), _slot4),  # q
+            pl.BlockSpec((1, ps, Hkv, hd), _pool_route),  # k_pool page
+            pl.BlockSpec((1, ps, Hkv, hd), _pool_route),  # v_pool page
+            pl.BlockSpec((1, Hkv, hd), _slot),  # k_new
+            pl.BlockSpec((1, Hkv, hd), _slot),  # v_new
+            pl.BlockSpec((1, Hkv, hd), _slot),  # kwrite
+            pl.BlockSpec((1, Hkv, hd), _slot),  # vwrite
         ],
         out_specs=[
-            pl.BlockSpec((1, G, hd), lambda b, h, p, *_: (b, h, 0)),  # o
-            pl.BlockSpec((1, G), lambda b, h, p, *_: (b, h)),  # m
-            pl.BlockSpec((1, G), lambda b, h, p, *_: (b, h)),  # l
+            pl.BlockSpec((1, Hkv, G, hd), _slot4),  # o
             pl.BlockSpec((1, 1, Hkv, hd), _write_route),  # k_pool row
             pl.BlockSpec((1, 1, Hkv, hd), _write_route),  # v_pool row
         ],
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),  # running max
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),  # running denominator
+        ],
     )
     kernel = functools.partial(
-        _decode_kernel, scale=scale, window=window, ps=ps, n_pages_slot=P)
-    o, _, _, k_out, v_out = pl.pallas_call(
+        _decode_kernel, scale=scale, window=window, ps=ps, n_pages_slot=P,
+        hkv=Hkv)
+    o, k_out, v_out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, hd), jnp.float32),
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
         # operands: 5 prefetch, then q=5 kpool=6 vpool=7 knew=8 vnew=9 ...
-        input_output_aliases={6: 3, 7: 4},
+        input_output_aliases={6: 1, 7: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(pt, pos, act, wpage, wrow, q, k_pool, v_pool, k_new, v_new,
-      kwrite, vwrite)
-    return o.astype(q.dtype), k_out, v_out
+    )(pt, pos, act, wpage, wrow, q.reshape(B, Hkv, G, hd), k_pool, v_pool,
+      k_new, v_new, kwrite, vwrite)
+    return o.reshape(B, Hq, hd).astype(q.dtype), k_out, v_out
 
 
 # ---------------------------------------------------------------------------

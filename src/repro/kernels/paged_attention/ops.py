@@ -1,5 +1,5 @@
 """Public paged-attention decode ops: Pallas on TPU, interpret-mode on CPU
-(`kernels.auto_interpret`, REPRO_PALLAS_INTERPRET overrides).
+(`kernels.auto_interpret`).
 
 models/attention.py dispatches here behind ``cache_update="kernel"``; the
 XLA "mask"/"scatter" paths stay as oracles (tests/test_paged_kernel.py).

@@ -13,6 +13,7 @@ laptop runs share code instead of each caller re-implementing the clamp.
 from __future__ import annotations
 
 import math
+import sys
 from typing import Sequence, Tuple
 
 import jax
@@ -35,7 +36,9 @@ def build_mesh(axes: Sequence[str], shape: Sequence[int], *,
     largest divisor of the remaining device count that does not exceed the
     requested extent — the smoke/laptop path (a 1-device box yields an
     all-ones mesh with the same axis names, so downstream code that looks
-    up axis extents keeps working).
+    up axis extents keeps working). A shrink that changes the shape says
+    so on stderr: on one chip the launcher's default data=2 becomes a
+    single client.
     """
     import numpy as np
 
@@ -55,6 +58,10 @@ def build_mesh(axes: Sequence[str], shape: Sequence[int], *,
                 s -= 1  # largest divisor of `left` that is <= requested
             fitted.append(s)
             left //= s
+        if tuple(fitted) != shape:
+            print(f"build_mesh: shrank {dict(zip(axes, shape))} to "
+                  f"{dict(zip(axes, fitted))} on {len(devs)} device(s)",
+                  file=sys.stderr)
         shape = tuple(fitted)
     n = math.prod(shape)
     if len(devs) < n:

@@ -41,6 +41,7 @@ from repro.core.driver import TrainDriver
 from repro.core.engine import EngineConfig, RoundEngine
 from repro.data.device import DeviceShards, host_stacked_batches
 from repro.data.synthetic import make_lm_tokens
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import (
     make_federated_mesh,
     make_host_mesh,
@@ -103,6 +104,7 @@ def main():
                          "(DESIGN.md §14): NaN checks armed and the run "
                          "must prove zero steady-state recompiles")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

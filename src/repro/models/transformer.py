@@ -648,7 +648,10 @@ def prefill(cfg, p, batch, impl="auto", window: int = 0, pad_to: int = 0, unroll
     def body(carry, lp):
         h = carry
         hn = apply_norm(cfg, lp["norm1"], h)
-        a_out = attn.attention_block(cfg, lp["attn"], hn, positions, impl=impl, window=window)
+        # W, not `window`: window=0 means "the config's own window" here,
+        # while attention_block reads 0 as full attention — an S > W prompt
+        # would otherwise attend past the window that decode enforces
+        a_out = attn.attention_block(cfg, lp["attn"], hn, positions, impl=impl, window=W)
         kv = attn.prefill_kv_cache(cfg, lp["attn"], hn, positions, window=W, pad_to=pad_to)
         new_ssm = None
         if cfg.hybrid_parallel_ssm:

@@ -4,7 +4,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _round_body(stacked, w):

@@ -249,15 +249,16 @@ def test_paged_serve_loop_kernel_stream_parity_swa():
 
 
 def test_auto_interpret_env_override(monkeypatch):
+    """Interpret mode is on exactly when the backend is the CPU; the old
+    REPRO_PALLAS_INTERPRET environment override is gone, so it cannot put
+    a kernel into the emulator on a TPU."""
     from repro import kernels
 
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    default = kernels.auto_interpret()
-    assert default == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert kernels.auto_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert kernels.auto_interpret() is False
+    assert kernels.auto_interpret() == (jax.default_backend() == "cpu")
+    for backend, want in (("cpu", True), ("tpu", False), ("gpu", False)):
+        monkeypatch.setattr(kernels.jax, "default_backend", lambda b=backend: b)
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1" if backend == "tpu" else "0")
+        assert kernels.auto_interpret() is want
 
 
 @pytest.mark.skipif(

@@ -138,7 +138,10 @@ def test_controller_tau_always_bounded(betas, deltas, alpha):
     assert np.all(tau >= cfg.tau_min)
     assert np.all(tau <= cfg.tau_max)
     if "alpha_k" in diag:
-        assert 0 < diag["alpha_k"] <= alpha + 1e-9
+        # the controller clamps in f32 (the device core's op order), so its
+        # ceiling is alpha rounded to f32 — up to half an f32 ulp above the
+        # float64 alpha drawn here (2.4e-10 at alpha=0.3228)
+        assert 0 < diag["alpha_k"] <= np.float32(alpha)
     # the arg-min-A client always gets the largest allowed tau
     A = diag["A"]
     if np.all(np.isfinite(A)) and A.max() > A.min() * (1 + 1e-6):

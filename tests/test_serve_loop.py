@@ -78,7 +78,14 @@ def test_moe_parity_when_capacity_never_binds():
     padded = jnp.zeros((1, 16), jnp.int32).at[0, :5].set(toks)
     lb, _ = model.prefill(params, {"tokens": padded}, pad_to=32,
                           length=jnp.array([5], jnp.int32))
-    np.testing.assert_array_equal(np.asarray(le), np.asarray(lb))
+    # Bucketed (S=16) and exact (S=5) prefills are different programs: XLA's
+    # CPU backend sums the attention softmax and the contractions over the
+    # padded length in a shape-dependent order, so the logits agree to f32
+    # rounding (measured gap 2.4e-6 on jax 0.9), not bitwise. The dense
+    # reduced qwen1.5 shows the same 1.6e-6 gap, so it is not the experts.
+    # Capacity never binding is pinned by the exact token streams below.
+    np.testing.assert_allclose(np.asarray(le), np.asarray(lb),
+                               rtol=1e-5, atol=1e-5)
 
     reqs = _trace(model, n=5)
     a, b = _clone(reqs), _clone(reqs)
@@ -345,7 +352,7 @@ def test_serve_example_exits_cleanly_for_whisper():
     env["PYTHONPATH"] = "src"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
-        [sys.executable, "examples/serve_decode.py", "--arch",
+        [sys.executable, "examples/serve_decode.py", "--reduced", "--arch",
          "whisper-medium"],
         capture_output=True, text=True, env=env, cwd=root, timeout=300,
     )

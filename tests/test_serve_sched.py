@@ -381,8 +381,8 @@ def test_serve_example_scheduler_flags_parity():
     env["PYTHONPATH"] = "src"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
-        [sys.executable, "examples/serve_decode.py", "--arch", "qwen1.5-32b",
-         "--paged", "--prefix-cache", "--prefill-chunk", "4", "--preempt",
+        [sys.executable, "examples/serve_decode.py", "--reduced",
+         "--arch", "qwen1.5-32b", "--paged", "--prefix-cache", "--prefill-chunk", "4", "--preempt",
          "--slots", "3", "--capacity", "64", "--page-size", "8",
          "--requests", "6", "--max-new", "8", "--check"],
         capture_output=True, text=True, env=env, cwd=root, timeout=600,

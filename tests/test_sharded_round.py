@@ -329,6 +329,23 @@ def test_sharded_fused_trajectory_matches_single_device(setup, cohort):
 
 
 @needs_devices
+def test_sharded_driver_compiles_the_round_once(setup):
+    """Round 0's params are placed where later rounds' come from
+    (replicated on the mesh), so the sharded round compiles once: the
+    sanitizer sees zero compiles after round 0."""
+    model, ds, p, _, _ = setup
+    mesh = make_federated_mesh(8)
+    eng = _engine(model, ds, mesh, donate=True,
+                  controller=ControllerCore(
+                      ControllerConfig(eta=0.05, tau_max=TAU_MAX), C,
+                      mesh=mesh))
+    drv = TrainDriver(eng, p, overlap=0, seed=0, sanitize=True)
+    log = drv.run(model.init(jax.random.PRNGKey(0)), 3,
+                  np.full(C, 2, np.int32))  # raises on a steady compile
+    assert len(log.rows) == 3
+
+
+@needs_devices
 def test_sharded_driver_end_to_end(setup):
     """TrainDriver over a sharded engine: overlap semantics hold (sync ==
     overlapped bit-for-bit) and losses stay finite."""
