@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.kernels import auto_interpret
 from repro.kernels.paged_attention import ref
 from repro.kernels.paged_attention.kernel import (
+    dma_walk_fits,
     paged_decode_attention_pallas,
     paged_insert_pallas,
 )
@@ -28,17 +29,21 @@ def paged_decode_attention(q, k_pool, v_pool, k_new, v_new, page_table, pos,
     q [B,Hq,hd], pools [N,ps,Hkv,hd], k_new/v_new [B,Hkv,hd],
     page_table [B,P] int32, pos [B]; active bool [B] (None = all live)
     -> (o [B,Hq,hd], k_pool', v_pool').
+
+    On the TPU, pools whose pages its DMA cannot move alone (head_dim off
+    the 128 lanes, or KV heads that leave a sublane tile padded:
+    ``dma_walk_fits``) take the XLA oracle path instead of the kernel.
     """
     B = q.shape[0]
     act = jnp.ones((B,), bool) if active is None else active
-    if not use_pallas:
+    interpret = auto_interpret() if interpret is None else interpret
+    if not use_pallas or not (interpret or dma_walk_fits(*k_pool.shape[2:])):
         return ref.paged_decode_attention(
             q, k_pool, v_pool, k_new, v_new, page_table, pos, act,
             window=window)
     return paged_decode_attention_pallas(
         q, k_pool, v_pool, k_new, v_new, page_table, pos, act,
-        window=int(window),
-        interpret=auto_interpret() if interpret is None else interpret)
+        window=int(window), interpret=interpret)
 
 
 def paged_insert(k_pool, v_pool, k_src, v_src, page_ids, *,

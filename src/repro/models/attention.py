@@ -343,9 +343,10 @@ def paged_decode_attention_block(cfg, p, x, pool: PagedKVPool, page_table,
     their outputs are garbage the caller must ignore.
 
     cache_update="kernel" routes to kernels/paged_attention: the Pallas
-    decode kernel walks the page table in-kernel (scalar prefetch) with
-    online-softmax accumulation — no [B, P*page_size, ...] gather — and
-    fuses the one-row pool write into the same launch. Pool bits are
+    decode kernel walks each slot's live pages only, a block of pages per
+    grid step by manual DMA, with online-softmax accumulation — no
+    [B, P*page_size, ...] gather — and writes the new row into the pool
+    in the same launch. Pool bits are
     identical to "mask"/"scatter"; the attention output reassociates the
     fp32 softmax reduction (ULP-level differences; greedy streams still
     match bit-for-bit, asserted in tests/test_paged_kernel.py).

@@ -49,6 +49,7 @@ from repro.analysis import sanitize as _sanitize
 # so the warning doesn't fire once per serve dispatch
 from repro.core.engine import _quiet_donation
 from repro.core.scheduler import AdmissionScheduler
+from repro.kernels.paged_attention.kernel import live_pages
 from repro.models.attention import KVCache
 from repro.models.model import Model, decode_capability
 from repro.models.transformer import (DecodeCache, insert_cache_pages,
@@ -575,6 +576,10 @@ class PagedServeLoop(ServeLoop):
             else None
         self.prefix_hit_tokens = 0
         self.preemptions = 0
+        # pages the decode kernel's walk bound reads, and pages the slots'
+        # tables list, summed over decode dispatches
+        self.kv_pages_walked = 0
+        self.kv_pages_listed = 0
         self.extend_dispatches = 0
         self.restore_dispatches = 0
 
@@ -842,6 +847,10 @@ class PagedServeLoop(ServeLoop):
 
     def _dispatch_decode(self, rid, nstep):
         table = self.table
+        self.kv_pages_walked += int(live_pages(
+            table.pos, table.active, self.page_size, self.cfg.sliding_window,
+            self.pages_per_slot, xp=np).sum())
+        self.kv_pages_listed += self.n_slots * self.pages_per_slot
         with _quiet_donation():
             return self._decode(
                 self.params, self.cache, jnp.asarray(self.page_table),
@@ -860,6 +869,8 @@ class PagedServeLoop(ServeLoop):
             preemptions=self.preemptions,
             extend_dispatches=self.extend_dispatches,
             restore_dispatches=self.restore_dispatches,
+            kv_pages_walked=self.kv_pages_walked,
+            kv_pages_listed=self.kv_pages_listed,
             prefix_pages=len(self.prefix) if self.prefix else 0,
         )
 

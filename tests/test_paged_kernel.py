@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.paged_attention import kernel as pa_kernel
 from repro.kernels.paged_attention import ops as pa_ops
 from repro.kernels.paged_attention import ref as pa_ref
 
@@ -105,6 +107,110 @@ def test_paged_decode_kernel_unallocated_and_recycled_pages(ps, window):
     # pos inside the still-allocated prefix
     pos = jnp.asarray([0, ps - 1, (P - 1) * ps - 1], jnp.int32)
     _compare(q, kp, vp, kn, vn, pt, pos, None, window)
+
+
+def _interpret_compare(q, kp, vp, kn, vn, pt, pos, active, window):
+    """The kernel under the TPU interpreter (DMAs, semaphores, races) vs
+    the float32 oracle on the same values: pools bitwise, live outputs
+    allclose (to the output's own rounding for bfloat16), no race."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as ipc
+
+    o_k, kk, vk = pa_kernel.paged_decode_attention_pallas(
+        q, kp, vp, kn, vn, pt, pos, active, window=window,
+        interpret=pltpu.InterpretParams(detect_races=True))
+    jax.block_until_ready(o_k)
+    assert not ipc.races.races_found
+    f32 = [x.astype(jnp.float32) for x in (q, kp, vp, kn, vn)]
+    o_r, kr, vr = pa_ref.paged_decode_attention(*f32, pt, pos, active,
+                                                window=window)
+    np.testing.assert_array_equal(np.asarray(kk),
+                                  np.asarray(kr.astype(kp.dtype)))
+    np.testing.assert_array_equal(np.asarray(vk),
+                                  np.asarray(vr.astype(vp.dtype)))
+    act = np.asarray(active)
+    tol = 1e-5 if q.dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(
+        np.asarray(o_k, np.float32)[act], np.asarray(o_r)[act],
+        atol=tol, rtol=tol)
+
+
+# (ps, P, window, pos, active, -1 entries as (slot, page)); at these page
+# sizes (two 128-wide heads) a block is K = BLOCK_BYTES // page bytes
+# pages: 4 of 64 rows in bfloat16, 2 in float32, so a slot spans blocks
+_WALK_CASES = {
+    # the walk ends inside a block, in the first, second and last block
+    "ends_mid_block": (64, 8, 0, [5, 300, 470], [1, 1, 1], []),
+    # the last entry of a block, the first of the next, the table's last
+    "ends_on_block_boundary": (64, 8, 0, [255, 256, 511], [1, 1, 1], []),
+    # SWA rings wrapped past the window: every page live
+    "swa_wrapped_all_live": (64, 8, 512, [512, 1000, 4097], [1, 1, 1], []),
+    # K = 2 or 4 does not divide P = 9: the last block holds one page
+    "ragged_last_block": (64, 9, 0, [575, 530, 100], [1, 1, 1], []),
+    # unallocated entries inside the live range stay masked
+    "unallocated_inside_live": (64, 8, 0, [300, 511, 64],
+                                [1, 1, 1], [(0, 1), (1, 4), (2, 0)]),
+    # inactive slots walk nothing, between and after live ones
+    "mixed_inactive": (64, 8, 512, [300, 700, 20], [0, 1, 0], []),
+    # no slot live: no DMA at all, pools untouched
+    "all_inactive": (64, 8, 0, [300, 511, 0], [0, 0, 0], []),
+    # one page per block at a large page
+    "page_per_block": (256, 3, 0, [700, 256, 10], [1, 0, 1], []),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_paged_decode_kernel_block_walk(case, dtype):
+    """bfloat16 pools with paired KV heads take the packed-word read
+    (heads split out of shared 32-bit words); float32 reads heads
+    directly. A swapped or misread head fails the output check."""
+    ps, P, window, pos, active, holes = _WALK_CASES[case]
+    B, Hkv = 3, 2
+    arrays = _scenario(P * 7 + ps, B, 4, Hkv, 128, B * P + 2, P, ps)
+    q, kp, vp, kn, vn = (x.astype(dtype) for x in arrays[:5])
+    pt = np.array(arrays[5])
+    for b, j in holes:
+        pt[b, j] = -1
+    _interpret_compare(q, kp, vp, kn, vn, jnp.asarray(pt),
+                       jnp.asarray(pos, jnp.int32),
+                       jnp.asarray(active, bool), window)
+
+
+@pytest.mark.parametrize("page_bytes", [512, 8192, 24576, 131072, 163840])
+@pytest.mark.parametrize("P", [1, 3, 16, 256, 257])
+def test_pages_per_block_rule(page_bytes, P):
+    """K follows the shapes: at least one page, at most the table, and no
+    more bytes than a block holds unless one page alone is larger (16
+    pages at starcoder2-3b's 8 KiB pages). The blocks cover the table
+    whether or not K divides it."""
+    K = pa_kernel.pages_per_block(P, page_bytes)
+    assert 1 <= K <= P
+    assert K * page_bytes <= max(pa_kernel.BLOCK_BYTES, page_bytes)
+    assert K == P or (K + 1) * page_bytes > pa_kernel.BLOCK_BYTES
+    assert -(-P // K) * K >= P
+    assert pa_kernel.pages_per_block(256, 16 * 2 * 128 * 2) == 16
+
+
+@pytest.mark.parametrize("window", [0, 12, 16])
+@pytest.mark.parametrize("ps", [1, 4, 16])
+def test_live_pages_is_the_last_valid_page(window, ps):
+    """The walk bound is exactly the pages up to the last entry that
+    paged_slot_valid admits; numpy and jax.numpy agree."""
+    P = max(1, -(-(window or 40) // ps))
+    pos = np.arange(0, 3 * P * ps + 5, dtype=np.int32)
+    pt = np.zeros((pos.size, P), np.int32)
+    valid = np.asarray(pa_ref.slot_valid(jnp.asarray(pt), jnp.asarray(pos),
+                                         ps, window))
+    last = np.where(valid.any(1),
+                    valid.shape[1] - 1 - np.argmax(valid[:, ::-1], 1), -1)
+    want = np.minimum(last // ps + 1, P)
+    act = np.ones(pos.size, bool)
+    got = pa_kernel.live_pages(pos, act, ps, window, P, xp=np)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        np.asarray(pa_kernel.live_pages(jnp.asarray(pos), jnp.asarray(act),
+                                        ps, window, P)), want)
+    assert not pa_kernel.live_pages(pos, ~act, ps, window, P, xp=np).any()
 
 
 @pytest.mark.parametrize("n_alloc", [0, 1, 3])

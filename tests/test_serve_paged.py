@@ -328,6 +328,32 @@ def test_sample_streams_independent_of_batch_composition():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("arch, walked", [("qwen1.5-32b", 15),
+                                           ("starcoder2-3b", 47)])
+def test_kv_page_counters_match_hand_count(arch, walked):
+    """Per decode dispatch, kv_pages_walked adds the pages the decode
+    kernel's walk bound reads for each live slot and kv_pages_listed the
+    slots' whole tables. Hand count at page size 4: a request's decode
+    dispatches sit at positions plen .. plen + len(out) - 2 and read
+    ceil((min(pos, W - 1) + 1) / 4) pages: A (plen 5) 2+2+2, B (plen 9)
+    3+3, C (plen 3, arriving later, into a freed slot) 1+2; starcoder2's
+    ring (W 64, 16 pages) adds D (plen 66, past the window) 16+16."""
+    model, params = _build(arch)
+    reqs = [Request(rid=0, tokens=np.arange(5) % 7, max_new=4),
+            Request(rid=1, tokens=np.arange(9) % 7, max_new=3),
+            Request(rid=2, tokens=np.arange(3) % 7, max_new=3, arrival=1)]
+    if model.config.sliding_window:
+        reqs.append(Request(rid=3, tokens=np.arange(66) % 7, max_new=3,
+                            arrival=1))
+    loop = PagedServeLoop(model, params, n_slots=2, capacity=16,
+                          page_size=4, cache_update="kernel")
+    stats = loop.run(reqs)
+    assert all(len(r.out) == r.max_new for r in reqs)
+    assert stats["kv_pages_walked"] == walked
+    assert stats["kv_pages_listed"] == \
+        stats["decode_dispatches"] * 2 * loop.pages_per_slot
+
+
 def test_paged_decode_bundle():
     from jax.sharding import Mesh
     from repro.configs.base import ShapeConfig
